@@ -1,4 +1,4 @@
-"""smollm-360m [dense] — llama-arch small, GQA. [hf:HuggingFaceTB/SmolLM-135M; hf]"""
+"""smollm-360m [dense] — llama-arch small, GQA. [hf:HuggingFaceTB/SmolLM-360M; hf]"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -13,5 +13,5 @@ CONFIG = ModelConfig(
     qkv_bias=False,
     rope_theta=10_000.0,
     tie_embeddings=True,
-    source="hf:HuggingFaceTB/SmolLM-135M; hf",
+    source="hf:HuggingFaceTB/SmolLM-360M; hf",
 )

@@ -32,25 +32,22 @@ import jax.numpy as jnp
 from repro.kernels._interpret import resolve_interpret
 from repro.kernels.tiered_gather.kernel import (
     gather_rows_kernel,
-    tiered_gather_kernel,
     tiered_segmented_kernel,
 )
 
 LANE = 128
 
 
-def _pad_lanes(x):
+def _rows3(x, dtype):
+    """An (M, D) store as the kernels' (M, 1, D_pad) row view; an empty
+    tier still needs one DMA-able dummy row."""
+    if x.shape[0] == 0:
+        x = jnp.zeros((1, x.shape[1]), dtype)
+    x = x.astype(dtype)
     pad = (-x.shape[-1]) % LANE
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
-    return x, pad
-
-
-def _nonempty(x, dtype):
-    """A (>=1, D) store: an empty tier still needs one DMA-able dummy row."""
-    if x.shape[0] == 0:
-        return jnp.zeros((1, x.shape[1]), dtype)
-    return x.astype(dtype)
+    return x[:, None, :]
 
 
 def gather_rows(src, ids, scales=None, *, interpret: Optional[bool] = None):
@@ -61,10 +58,10 @@ def gather_rows(src, ids, scales=None, *, interpret: Optional[bool] = None):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _gather_rows(src, ids, scales, *, interpret):
     d = src.shape[1]
-    srcp, _ = _pad_lanes(src)
-    sc = None if scales is None else scales.reshape(-1, 1).astype(jnp.float32)
-    out = gather_rows_kernel(srcp, ids.astype(jnp.int32), sc, interpret=interpret)
-    return out[:, :d]
+    ids = ids.astype(jnp.int32)
+    sc = None if scales is None else scales.reshape(-1).astype(jnp.float32)[ids]
+    out = gather_rows_kernel(_rows3(src, src.dtype), ids, sc, interpret=interpret)
+    return out[:, 0, :d]
 
 
 def tiered_lookup_counted(hot, cold_q, cold_scales, tier, slot, ids,
@@ -73,17 +70,20 @@ def tiered_lookup_counted(hot, cold_q, cold_scales, tier, slot, ids,
     int8 ``cold_q``+``cold_scales`` store, selected by ``tier``/``slot`` maps.
 
     Returns (rows (N, D) f32, near_hits int32 scalar, far_hits int32 scalar):
-    the hit split is counted inside the kernel, at the access point. On real
-    hardware the two gathers run on separate streams (HBM vs host DMA); here
-    both tiers are DMA'd through one fused pass and merged by the tier bit.
+    the hit split is counted inside the kernel, at the access point — the
+    segmented pass with every gather in one segment. On real hardware the
+    two gathers run on separate streams (HBM vs host DMA); here both tiers
+    are DMA'd through one fused pass and merged by the tier bit.
     """
     if ids.shape[0] == 0:
         z = jnp.zeros((), jnp.int32)
         return jnp.zeros((0, hot.shape[1]), jnp.float32), z, z
-    rows, near = _tiered_lookup(
-        hot, cold_q, cold_scales, tier, slot, ids, interpret=resolve_interpret(interpret)
+    rows, hits = _tiered_lookup_segments(
+        hot, cold_q, cold_scales, tier, slot, ids,
+        jnp.zeros(ids.shape, jnp.int32),
+        n_segments=1, interpret=resolve_interpret(interpret),
     )
-    return rows, near, jnp.int32(ids.shape[0]) - near
+    return rows, hits[0, 0], hits[0, 1]
 
 
 def tiered_lookup_segments(hot, cold_q, cold_scales, tier, slot, ids, seg_of,
@@ -117,23 +117,22 @@ def _tiered_lookup_segments(hot, cold_q, cold_scales, tier, slot, ids, seg_of,
     ids = ids.astype(jnp.int32)
     t = tier[ids].astype(jnp.int32)
     s = slot[ids].astype(jnp.int32)
-    hotp, _ = _pad_lanes(_nonempty(hot, hot.dtype))
-    coldp, _ = _pad_lanes(_nonempty(cold_q, jnp.int8))
+    cold_ids = jnp.where(t == 1, s, 0)
     scales = cold_scales.reshape(-1).astype(jnp.float32)
     if scales.shape[0] == 0:
         scales = jnp.ones((1,), jnp.float32)
     rows, seg_hits = tiered_segmented_kernel(
-        hotp,
-        coldp,
-        scales.reshape(-1, 1),
+        _rows3(hot, hot.dtype),
+        _rows3(cold_q, jnp.int8),
+        scales[cold_ids],
         t,
         jnp.where(t == 0, s, 0),
-        jnp.where(t == 1, s, 0),
+        cold_ids,
         seg_of.astype(jnp.int32),
         n_segments,
         interpret=interpret,
     )
-    return rows[:, :d], seg_hits
+    return rows[:, 0, :d], seg_hits
 
 
 def tiered_lookup(hot, cold_q, cold_scales, tier, slot, ids,
@@ -142,26 +141,3 @@ def tiered_lookup(hot, cold_q, cold_scales, tier, slot, ids,
     return tiered_lookup_counted(
         hot, cold_q, cold_scales, tier, slot, ids, interpret=interpret
     )[0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _tiered_lookup(hot, cold_q, cold_scales, tier, slot, ids, *, interpret):
-    d = hot.shape[1]
-    ids = ids.astype(jnp.int32)
-    t = tier[ids].astype(jnp.int32)
-    s = slot[ids].astype(jnp.int32)
-    hotp, _ = _pad_lanes(_nonempty(hot, hot.dtype))
-    coldp, _ = _pad_lanes(_nonempty(cold_q, jnp.int8))
-    scales = cold_scales.reshape(-1).astype(jnp.float32)
-    if scales.shape[0] == 0:
-        scales = jnp.ones((1,), jnp.float32)
-    rows, hits = tiered_gather_kernel(
-        hotp,
-        coldp,
-        scales.reshape(-1, 1),
-        t,
-        jnp.where(t == 0, s, 0),
-        jnp.where(t == 1, s, 0),
-        interpret=interpret,
-    )
-    return rows[:, :d], hits[0, 0]

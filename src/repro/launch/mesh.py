@@ -19,7 +19,7 @@ from typing import Optional, Sequence, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 AxisName = Union[str, tuple, None]
 
@@ -43,7 +43,7 @@ def make_production_mesh(*, multi_pod: bool = False, pool: int = 0) -> Mesh:
     else:
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> Mesh:
@@ -61,7 +61,13 @@ def make_host_mesh(model: int = 1) -> Mesh:
             f"an (n // model, model) mesh would silently drop {dropped} "
             "device(s). Pick a model-axis size that divides the device count."
         )
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes: its default is ``Explicit``
+    axes, which ``with_sharding_constraint`` (``shard`` below) refuses."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_serving_mesh(model: int = 1) -> Mesh:
@@ -89,16 +95,20 @@ def shard_model_params(params, mesh: Mesh, axis: str = MODEL):
     without per-call constraint calls. On a 1-device mesh this is a pure
     device_put: values (and therefore decoded tokens) are bit-identical to
     the unsharded engine."""
+    return jax.device_put(params, model_shardings(params, mesh, axis))
+
+
+def model_shardings(tree, mesh: Mesh, axis: str = MODEL):
+    """The :func:`shard_model_params` layout as a pytree of shardings, for
+    arrays or shape stand-ins (``jax.eval_shape``) alike."""
     size = int(mesh.shape[axis])
 
-    def put(x):
+    def one(x):
         if getattr(x, "ndim", 0) >= 1 and size > 1 and x.shape[-1] % size == 0:
-            s = NamedSharding(mesh, P(*([None] * (x.ndim - 1) + [axis])))
-        else:
-            s = NamedSharding(mesh, P())
-        return jax.device_put(x, s)
+            return NamedSharding(mesh, P(*([None] * (x.ndim - 1) + [axis])))
+        return NamedSharding(mesh, P())
 
-    return jax.tree.map(put, params)
+    return jax.tree.map(one, tree)
 
 
 # ---------------------------------------------------------------------------

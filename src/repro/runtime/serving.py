@@ -260,7 +260,7 @@ class ServingEngine:
         self.profiler = AccessProfiler(e.n_pages, self._page_bytes(), window_len=e.placement_window)
         self.tracer = MemTracer(e.trace_window, e.trace_period)
         self.slots = [_Slot() for _ in range(e.max_batch)]
-        self.cache = api.init_cache(e.max_batch, e.max_len)
+        self.cache = self._make_cache()
         # deque, not list: _admit pops the head every step, and a list's
         # pop(0) makes admission O(n^2) under backlog
         self.queue: Deque[Request] = deque()
@@ -443,6 +443,11 @@ class ServingEngine:
             # initial fill: position the starting near set without charging
             # it to the migration books (nothing has been written yet)
             self.tiered.migrate(self.placement.near_blocks(), account=False)
+
+    def _make_cache(self):
+        """Allocate the batched slot cache. Overridable seam: the sharded
+        engine places it on its mesh."""
+        return self.api.init_cache(self.ecfg.max_batch, self.ecfg.max_len)
 
     def _make_tiered_store(self):
         """Build the device-resident tiered store. Overridable seam: the

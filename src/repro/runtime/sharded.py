@@ -52,10 +52,16 @@ from __future__ import annotations
 
 from typing import List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.launch.mesh import activate, make_serving_mesh, shard_model_params
+from repro.launch.mesh import (
+    activate,
+    make_serving_mesh,
+    model_shardings,
+    shard_model_params,
+)
 from repro.runtime.serving import EngineConfig, ServingEngine
 from repro.runtime.tiered_kv import (
     N_ROLES,
@@ -96,6 +102,7 @@ class ShardedTieredKV:
         identity_scales: bool = False,
         interpret: Optional[bool] = None,
         counter_slots: int = 0,
+        devices=None,
     ):
         if n_shards < 1 or n_pages % n_shards != 0:
             raise ValueError(
@@ -109,6 +116,12 @@ class ShardedTieredKV:
         self.identity_scales = identity_scales
         self.interpret = interpret
         n_local = n_pages // n_shards
+        # shard s lives on devices[s] (None: every shard on the default
+        # device); merged rows are gathered onto the first shard's device
+        devices = list(devices) if devices is not None else [None] * n_shards
+        if len(devices) != n_shards:
+            raise ValueError(f"{len(devices)} devices for {n_shards} shards")
+        self.home = devices[0]
         self.shards = [
             TieredKVCache(
                 n_local,
@@ -118,8 +131,9 @@ class ShardedTieredKV:
                 identity_scales=identity_scales,
                 interpret=interpret,
                 counter_slots=counter_slots,
+                device=dev,
             )
-            for _ in range(n_shards)
+            for dev in devices
         ]
         # per-shard drained (near, far) deltas pending consumption by the
         # engine's shard-labeled metric rows (take_shard_drains)
@@ -175,6 +189,16 @@ class ShardedTieredKV:
     def _owner(self, ids: np.ndarray) -> np.ndarray:
         return ids % self.n_shards
 
+    def _merge_rows(self, n: int, parts) -> jnp.ndarray:
+        """Scatter per-shard ``(positions, rows)`` back into global order,
+        on the home device."""
+        out = jnp.zeros((n, self.row_dim), jnp.float32)
+        for idx, rows in parts:
+            if self.home is not None:
+                rows = jax.device_put(rows, self.home)
+            out = out.at[jnp.asarray(idx)].set(rows)
+        return out
+
     def snap(self, rows):
         return self.shards[0].snap(rows)
 
@@ -209,7 +233,7 @@ class ShardedTieredKV:
         seg = np.asarray(seg_of, np.int32).reshape(-1)
         if ids.size == 0:
             return jnp.zeros((0, self.row_dim), jnp.float32)
-        out = jnp.zeros((ids.size, self.row_dim), jnp.float32)
+        parts = []
         owner = self._owner(ids)
         for s, sh in enumerate(self.shards):
             idx = np.flatnonzero(owner == s)
@@ -219,13 +243,13 @@ class ShardedTieredKV:
                 ids[idx] // self.n_shards, seg[idx], n_segments,
                 slot_idx=slot_idx, tenant_idx=tenant_idx, role_idx=role_idx,
             )
-            out = out.at[jnp.asarray(idx)].set(rows)
-        return out
+            parts.append((idx, rows))
+        return self._merge_rows(ids.size, parts)
 
     def lookup(self, page_ids):
         """Per-call (baseline) path: fan out, merge rows + host-int hits."""
         ids = np.asarray(page_ids, np.int64).reshape(-1)
-        rows = jnp.zeros((ids.size, self.row_dim), jnp.float32)
+        parts = []
         near = far = 0
         owner = self._owner(ids)
         for s, sh in enumerate(self.shards):
@@ -233,22 +257,20 @@ class ShardedTieredKV:
             if idx.size == 0:
                 continue
             r, n, f = sh.lookup(ids[idx] // self.n_shards)
-            rows = rows.at[jnp.asarray(idx)].set(r)
+            parts.append((idx, r))
             near += n
             far += f
-        return rows, near, far
+        return self._merge_rows(ids.size, parts), near, far
 
     def lookup_flat(self, page_ids):
         ids = np.asarray(page_ids, np.int64).reshape(-1)
-        rows = jnp.zeros((ids.size, self.row_dim), jnp.float32)
         owner = self._owner(ids)
+        parts = []
         for s, sh in enumerate(self.shards):
             idx = np.flatnonzero(owner == s)
             if idx.size:
-                rows = rows.at[jnp.asarray(idx)].set(
-                    sh.lookup_flat(ids[idx] // self.n_shards)
-                )
-        return rows
+                parts.append((idx, sh.lookup_flat(ids[idx] // self.n_shards)))
+        return self._merge_rows(ids.size, parts)
 
     def max_abs_error(self, page_ids) -> float:
         ids = np.asarray(page_ids, np.int64).reshape(-1)
@@ -405,7 +427,20 @@ class ShardedServingEngine(ServingEngine):
             max(1, int(e.model_shards)),
             identity_scales=e.tiered_identity_scales,
             counter_slots=e.max_batch,
+            devices=list(self.mesh.devices.flat),
         )
+
+    def _make_cache(self):
+        # the slot cache takes the parameters' layout on the mesh, built
+        # in place rather than on the default device and then moved
+        e = self.ecfg
+        shardings = model_shardings(
+            self.api.abstract_cache(e.max_batch, e.max_len), self.mesh
+        )
+        return jax.jit(
+            lambda: self.api.init_cache(e.max_batch, e.max_len),
+            out_shardings=shardings,
+        )()
 
     def step(self) -> int:
         # the whole step — admit, chunk/decode dispatch, segmented gather,

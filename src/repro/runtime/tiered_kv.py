@@ -105,6 +105,7 @@ class TieredKVCache:
         identity_scales: bool = False,
         interpret: Optional[bool] = None,
         counter_slots: int = 0,
+        device=None,
     ):
         assert 0 < near_capacity <= n_pages
         self.n_pages = n_pages
@@ -112,11 +113,15 @@ class TieredKVCache:
         self.near_capacity = near_capacity
         self.identity_scales = identity_scales
         self.interpret = interpret
+        # the device that holds this store (None: JAX's default device).
+        # Committing the stores there makes every later update and lookup
+        # run there too; only incoming payload rows need moving (write).
+        self.device = device
         # device stores
-        self.near = jnp.zeros((near_capacity, row_dim), near_dtype)
-        self.far_q = jnp.zeros((n_pages, row_dim), jnp.int8)
-        self.far_scale = jnp.ones((n_pages,), jnp.float32)
-        self.flat = jnp.zeros((n_pages, row_dim), jnp.float32)
+        self.near = self._put(jnp.zeros((near_capacity, row_dim), near_dtype))
+        self.far_q = self._put(jnp.zeros((n_pages, row_dim), jnp.int8))
+        self.far_scale = self._put(jnp.ones((n_pages,), jnp.float32))
+        self.flat = self._put(jnp.zeros((n_pages, row_dim), jnp.float32))
         # host mirrors of the device maps (slot allocation is host-side
         # bookkeeping, exactly like the page table itself)
         self.tier_host = np.full(n_pages, FAR, np.int32)
@@ -141,14 +146,14 @@ class TieredKVCache:
         # (near, far) hit pairs. The slot plane is indexed by engine decode
         # slot, the tenant plane by a caller-assigned tenant index; both
         # grow on demand and are only read by drain_counters().
-        self.ctr_slot = jnp.zeros((int(counter_slots), 2), jnp.int32)
-        self.ctr_tenant = jnp.zeros((0, 2), jnp.int32)
+        self.ctr_slot = self._put(jnp.zeros((int(counter_slots), 2), jnp.int32))
+        self.ctr_tenant = self._put(jnp.zeros((0, 2), jnp.int32))
         # per-ROLE accumulator: row 0 = decode segments, row 1 = prefill
         # chunks — the continuous-batching step carries a role alongside
         # each segment index so mixed prefill/decode dispatches stay
         # attributable without a second kernel pass
-        self.ctr_role = jnp.zeros((N_ROLES, 2), jnp.int32)
-        self.ctr_total = jnp.zeros((2,), jnp.int32)
+        self.ctr_role = self._put(jnp.zeros((N_ROLES, 2), jnp.int32))
+        self.ctr_total = self._put(jnp.zeros((2,), jnp.int32))
         self._plane_dirty = False
         # degraded far-tier-only mode: the near tier is capacity-zeroed at
         # runtime (host poisoned / HBM partition lost). While set, every
@@ -157,6 +162,9 @@ class TieredKVCache:
         self.degraded = False
 
     # ------------------------------------------------------------------
+    def _put(self, x):
+        return x if self.device is None else jax.device_put(x, self.device)
+
     @property
     def near_row_bytes(self) -> int:
         """Bytes a promotion writes into the near tier (f32/bf16 row)."""
@@ -212,7 +220,7 @@ class TieredKVCache:
         Duplicate ids keep the last row (page-table writes are ordered).
         """
         pids = np.asarray(page_ids, np.int64).reshape(-1)
-        rows = self.snap(jnp.asarray(rows).reshape(pids.size, self.row_dim))
+        rows = self.snap(self._put(jnp.asarray(rows)).reshape(pids.size, self.row_dim))
         if pids.size == 0:
             return
         # keep the LAST write per page id
@@ -379,10 +387,10 @@ class TieredKVCache:
                 (self.ctr_slot, self.ctr_tenant, self.ctr_role, self.ctr_total)
             )
         )
-        self.ctr_slot = jnp.zeros_like(self.ctr_slot)
-        self.ctr_tenant = jnp.zeros_like(self.ctr_tenant)
-        self.ctr_role = jnp.zeros_like(self.ctr_role)
-        self.ctr_total = jnp.zeros_like(self.ctr_total)
+        self.ctr_slot = self._put(jnp.zeros_like(self.ctr_slot))
+        self.ctr_tenant = self._put(jnp.zeros_like(self.ctr_tenant))
+        self.ctr_role = self._put(jnp.zeros_like(self.ctr_role))
+        self.ctr_total = self._put(jnp.zeros_like(self.ctr_total))
         self._plane_dirty = False
         n, f = int(total[0]), int(total[1])
         if not discard:

@@ -3,7 +3,8 @@ placement, prefetch, page table, memtrace — with hypothesis property tests."""
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import distribution as dist
 from repro.core import hw

@@ -19,7 +19,8 @@ import collections
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.core.memtrace import TraceWindow
 from repro.core.prefetch import PrefetchEngine, PrefetchStats, train_successors
 from repro.fleet import aggregator
@@ -145,7 +146,7 @@ def test_evict_counts_as_waste():
 
 
 # ---------------------------------------------------------------------------
-# property tests (hypothesis when available, deterministic replay otherwise)
+# property tests
 
 
 @settings(max_examples=40)

@@ -5,9 +5,7 @@ Three layers of oracle, matching how the path is built:
 1. kernel vs pure-jnp ref — ``tiered_lookup_counted`` against
    ``tiered_lookup_counted_ref`` across dtypes (f32/bf16 near, int8 far),
    ragged/duplicate id sets, empty-near / all-near / all-far edge cases,
-   and int8 scale round-trip error bounds. Property-style via the
-   ``_hypothesis_compat`` shim so the sweep runs with and without
-   hypothesis installed.
+   and int8 scale round-trip error bounds, as hypothesis properties.
 2. engine equivalence — a seeded ``ServingEngine.run`` with device tiering
    (identity scales: quantization error zeroed) must emit the SAME tokens
    and the SAME tier-hit counters as the host-accounted path; the
@@ -25,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs import get_config
 from repro.configs.workloads import get_profile

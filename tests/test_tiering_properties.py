@@ -1,8 +1,6 @@
 """Property tests for core/tiering.plan invariants (paper §5).
 
-Runs under real hypothesis when installed, else the deterministic replay
-shim in tests/_hypothesis_compat.py — CI exercises both paths. The
-invariants the fleet AutoTierer leans on:
+The invariants the fleet AutoTierer leans on:
 
 * the near set never exceeds the near tier's planned capacity;
 * the near set is exactly the top-k of the measured histogram (tie-robust:
@@ -13,7 +11,8 @@ invariants the fleet AutoTierer leans on:
 """
 import numpy as np
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.hw import TierSpec
 from repro.core.tiering import plan
