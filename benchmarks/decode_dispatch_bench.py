@@ -24,10 +24,10 @@ p99 time-to-first-token. The chunked win is structural, and honest about
 its mechanism: the whole-slot path pays one extra blocking model dispatch
 per admit (its admit argmax is a host sync) and an XLA compile per
 distinct prompt length, while the chunked engine only ever runs two decode
-shapes — (B, 1) and (B, C) — and admits with zero host syncs. Chunked
-TTFT is stamped when the engine observes the first token's dispatch (its
-step pipeline never blocks), whole-slot TTFT at its admit-time sync; both
-are the earliest instant each engine design can know the token exists.
+shapes — (B, 1) and (B, C) — and admits with zero host syncs. TTFT runs
+from submit to the step whose ``next_tokens`` readback first carries the
+request's token: the loop reads the tokens to the host after every step,
+as a streaming front end must, for both engines.
 
 Emits ``BENCH_decode.json`` next to this file — the decode dispatch-budget
 baseline the next perf PR regresses against. Self-checks: the segmented
@@ -40,7 +40,6 @@ import json
 import pathlib
 import time
 
-import jax
 import numpy as np
 
 from repro.configs.workloads import get_profile
@@ -134,8 +133,9 @@ def _access_many_microbench(n_slots=16, n_steps=120, chain=56, n_pages=4096):
 
 def _run_offered(mode: str, rate: int, n_requests=48, seed=0):
     """Sustained open-loop offered load: ``rate`` submits per engine step
-    from a long-prompt mix, measured wall-clock end to end (final state
-    block_until_ready'd so async dispatches are paid inside the window)."""
+    from a long-prompt mix, measured wall-clock end to end. Every step's
+    ``next_tokens`` is read to the host, and a request's first token is
+    stamped at the first readback that carries it."""
     cfg, eng = engine_for(
         seed=seed,
         max_batch=16,
@@ -154,18 +154,30 @@ def _run_offered(mode: str, rate: int, n_requests=48, seed=0):
     gen = RequestGenerator(prof, vocab_size=cfg.vocab_size, seed=seed)
     reqs = [next(gen) for _ in range(n_requests)]
     t0 = time.time()
-    submitted = step = 0
+    submitted = step = n_finished = 0
+    submit_t = {}  # rid -> submit time
+    ttft = []
     while submitted < len(reqs) or eng.queue or any(s.active for s in eng.slots):
         while submitted < len(reqs) and submitted < rate * (step + 1):
             eng.submit(reqs[submitted])
+            submit_t[reqs[submitted].rid] = time.time()
             submitted += 1
         eng.step()
         step += 1
+        np.asarray(eng.next_tokens)  # the step's tokens reach the host
+        t = time.time()
+        # a request has its first token once its prompt is in: it decodes,
+        # or it finished in this very step
+        have = [s.seq_id for s in eng.slots if s.active and not s.prefilling]
+        have += eng.finished[n_finished:]
+        n_finished = len(eng.finished)
+        for rid in have:
+            if rid in submit_t:
+                ttft.append(t - submit_t.pop(rid))
         if step > 4000:
             break
-    jax.block_until_ready(eng.next_tokens)
     dt = time.time() - t0
-    ttft = np.asarray(eng.ttft_wall_samples)
+    ttft = np.asarray(ttft)
     sv = eng.stats()["serving"]
     return {
         "tokens": eng.tokens_decoded,

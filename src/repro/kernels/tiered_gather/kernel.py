@@ -112,7 +112,8 @@ def _tiered_seg_kernel(tier_ref, hot_ids_ref, cold_ids_ref, seg_ref, scale_ref,
 
 
 def tiered_segmented_kernel(hot, cold_q, gather_scales, tier_sel, hot_ids,
-                            cold_ids, seg_of, n_segments, *, interpret=None):
+                            cold_ids, seg_of, n_segments, *, interpret=None,
+                            name="tiered_gather_segmented"):
     """Ragged (segmented) two-tier gather with per-segment hit counting.
 
     hot: (Mh, 1, D) f32/bf16; cold_q: (Mc, 1, D) int8; gather_scales: (N,)
@@ -125,7 +126,9 @@ def tiered_segmented_kernel(hot, cold_q, gather_scales, tier_sel, hot_ids,
     index, so it is carried across the sequential grid steps and
     accumulated by the same pass that DMAs the rows. Callers batching
     ragged id sets to a fixed bucket size point the padding at a
-    sacrificial segment and slice it off.
+    sacrificial segment and slice it off. ``name`` is the kernel's name in
+    the compiled program, and so the name its operation carries in a
+    device trace.
 
     Returns (rows (N, 1, D) f32, seg_hits (n_segments, 2) int32).
     """
@@ -165,4 +168,5 @@ def tiered_segmented_kernel(hot, cold_q, gather_scales, tier_sel, hot_ids,
             jax.ShapeDtypeStruct((n_segments, 2), jnp.int32),
         ],
         interpret=interpret,
+        name=name,
     )(tier_sel, hot_ids, cold_ids, seg_of, gather_scales, hot, cold_q)

@@ -36,6 +36,9 @@ from repro.kernels.tiered_gather.kernel import (
 )
 
 LANE = 128
+# the kernels' names in the compiled program and in a device trace
+SEGMENTED_KERNEL = "tiered_gather_segmented"
+LOOKUP_KERNEL = "tiered_gather_lookup"
 
 
 def _rows3(x, dtype):
@@ -82,6 +85,7 @@ def tiered_lookup_counted(hot, cold_q, cold_scales, tier, slot, ids,
         hot, cold_q, cold_scales, tier, slot, ids,
         jnp.zeros(ids.shape, jnp.int32),
         n_segments=1, interpret=resolve_interpret(interpret),
+        kernel_name=LOOKUP_KERNEL,
     )
     return rows, hits[0, 0], hits[0, 1]
 
@@ -107,12 +111,15 @@ def tiered_lookup_segments(hot, cold_q, cold_scales, tier, slot, ids, seg_of,
     return _tiered_lookup_segments(
         hot, cold_q, cold_scales, tier, slot, ids, seg_of,
         n_segments=n_segments, interpret=resolve_interpret(interpret),
+        kernel_name=SEGMENTED_KERNEL,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("n_segments", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("n_segments", "interpret", "kernel_name")
+)
 def _tiered_lookup_segments(hot, cold_q, cold_scales, tier, slot, ids, seg_of,
-                            *, n_segments, interpret):
+                            *, n_segments, interpret, kernel_name):
     d = hot.shape[1]
     ids = ids.astype(jnp.int32)
     t = tier[ids].astype(jnp.int32)
@@ -131,6 +138,7 @@ def _tiered_lookup_segments(hot, cold_q, cold_scales, tier, slot, ids, seg_of,
         seg_of.astype(jnp.int32),
         n_segments,
         interpret=interpret,
+        name=kernel_name,
     )
     return rows[:, 0, :d], seg_hits
 

@@ -23,7 +23,12 @@ scheduling, elasticity, the serving engine, and the tiered-KV drain path:
   time; the prompt-completing chunk step under chunked prefill), merged
   into ``tenant_report``'s ``ttft_p50``/``ttft_p99``;
 * ``export``  — Perfetto/Chrome trace_event JSON for the span timeline and
-  JSON-lines metric snapshots per profiler window.
+  JSON-lines metric snapshots per profiler window;
+* ``phase``   — wall-clock phases of the host path (off unless the recorder
+  is built with ``phases=True``): each opens a ``jax.profiler``
+  annotation, so on a profiled run it lands on the host plane on the device
+  trace's clock, and adds its ``perf_counter_ns`` time to the
+  ``phase_ns`` / ``phase_calls`` counters, which cover the whole run.
 
 :class:`FlightRecorder` is the facade the fleet attaches
 (``FleetRouter.attach_recorder`` / ``build_fleet(recorder=...)``); a
@@ -34,7 +39,11 @@ the dispatch-budget suite with tracing on).
 """
 from __future__ import annotations
 
-from typing import List, Optional
+import contextlib
+import time
+from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.env import env_flag
 from repro.obs import export as export_mod
@@ -64,11 +73,55 @@ __all__ = [
     "Span",
     "SpanRecorder",
     "FlightRecorder",
+    "PHASES_OFF",
     "default_recorder",
     "set_default_recorder",
 ]
 
 _ENV_FLAG = "REPRO_FLIGHT_RECORDER"
+
+
+class Phase:
+    """One timed host phase: a profiler annotation plus two counters.
+
+    ``kind`` (given at entry, or by :meth:`set_kind` once known) labels the
+    counters next to ``phase`` and rides the annotation as an argument;
+    every other argument only annotates."""
+
+    __slots__ = ("_counters", "_name", "_kind", "_ann", "_t0")
+
+    def __init__(self, counters, name: str, args: dict):
+        self._counters = counters
+        self._name = name
+        self._kind = args.get("kind")
+        self._ann = TraceAnnotation(name, **args)
+
+    def set_kind(self, kind: str):
+        self._kind = kind
+        self._ann.set_metadata(kind=kind)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        ns, calls = self._counters(self._name, self._kind)
+        ns.inc(time.perf_counter_ns() - self._t0)
+        calls.inc()
+        self._ann.__exit__(*exc)
+
+
+class _NoPhase:
+    """What a phase is while phases are off: nothing to label."""
+
+    def set_kind(self, kind: str):
+        pass
+
+
+# the one context every phase hook returns while phases are off: no clock
+# read, no annotation built
+PHASES_OFF = contextlib.nullcontext(_NoPhase())
 
 
 class FlightRecorder:
@@ -85,6 +138,7 @@ class FlightRecorder:
         capacity: int = 65536,
         metrics_window: float = 16.0,
         step_spans: bool = True,
+        phases: bool = False,
     ):
         self.spans = SpanRecorder(capacity)
         self.metrics = MetricsRegistry()
@@ -92,6 +146,8 @@ class FlightRecorder:
         self.metrics_window = float(metrics_window)
         self.metric_rows: List[dict] = []
         self.step_spans = bool(step_spans)  # per-replica step spans on host tracks
+        self.phases = bool(phases)  # wall-clock host phases (``phase``)
+        self._phase_counters: Dict[Tuple[str, Optional[str]], tuple] = {}
         self.now_fn = lambda: 0.0
         self._last_window: Optional[float] = None
 
@@ -116,6 +172,26 @@ class FlightRecorder:
 
     def span(self, name, trace, t0, t1, **kw):
         self.spans.span(name, trace, t0, t1, **kw)
+
+    # wall-clock phases ------------------------------------------------
+    def phase(self, name: str, **args):
+        """Time one host phase on the wall clock: a ``jax.profiler``
+        annotation named ``name`` (``args`` as its arguments) and, on exit,
+        ``phase_ns`` and ``phase_calls`` labeled ``phase=name`` (and
+        ``kind=`` when the phase has one). Callers check ``phases`` first;
+        with it off they use :data:`PHASES_OFF` instead."""
+        return Phase(self._counters_for, name, args)
+
+    def _counters_for(self, name: str, kind: Optional[str]) -> tuple:
+        key = (name, kind)
+        pair = self._phase_counters.get(key)
+        if pair is None:
+            labels = {"phase": name} if kind is None else {"phase": name, "kind": kind}
+            pair = self._phase_counters[key] = (
+                self.metrics.counter("phase_ns", **labels),
+                self.metrics.counter("phase_calls", **labels),
+            )
+        return pair
 
     # metrics snapshots -------------------------------------------------
     def on_step(self, now: float):

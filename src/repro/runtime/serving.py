@@ -66,7 +66,6 @@ equivalence baseline.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
@@ -82,7 +81,7 @@ from repro.core.prefetch import PrefetchEngine, train_tenant_successors
 from repro.core.profiler import AccessProfiler
 from repro.data.requests import ChunkState, Request, RequestGenerator
 from repro.env import env_flag
-from repro.obs import Counter, MetricsRegistry, default_recorder
+from repro.obs import PHASES_OFF, Counter, MetricsRegistry, default_recorder
 from repro.models.api import ModelAPI, make_serve_step
 from repro.runtime.tiered_kv import (
     N_ROLES,
@@ -284,6 +283,18 @@ class ServingEngine:
         self.recorder = recorder if recorder is not None else default_recorder()
         if self.recorder is not None:
             self.recorder.register(self.metrics)
+        # wall-clock phases of step() (obs.FlightRecorder.phase), bound once:
+        # without a recorder whose phases are on, every phase is the one
+        # shared null context, so a step reads no clock and builds no
+        # profiler annotation
+        if self.recorder is not None and self.recorder.phases:
+            self._phase = self.recorder.phase
+        else:
+            self._phase = lambda name, **args: PHASES_OFF
+        self._m_steps = {
+            kind: self.metrics.counter("engine_steps", kind=kind)
+            for kind in ("decode", "chunk")
+        }
         # set by the fleet: replica id for span tracks, and the shared
         # virtual clock (None -> engine steps stand in for time)
         self.host_rid = -1
@@ -342,13 +353,10 @@ class ServingEngine:
         # time-to-first-token: stamped at submit(), recorded the moment a
         # request's first generated token exists (admit-time under the
         # whole-slot path; the prompt-completing chunk step under chunked
-        # prefill). Virtual-time samples feed the per-tenant "ttft"
-        # histogram + the pinning test; wall-clock samples feed the
-        # offered-load benchmark cells.
+        # prefill), in virtual time. The samples feed the per-tenant "ttft"
+        # histogram + the pinning test.
         self._enq_vt: Dict[int, float] = {}
-        self._enq_wall: Dict[int, float] = {}
         self.ttft_vt_samples: List[float] = []
-        self.ttft_wall_samples: List[float] = []
         # per-role (decode, prefill) x (near, far) tier hits drained from
         # the device counter plane's role accumulator
         self.role_hits = np.zeros((N_ROLES, 2), np.int64)
@@ -545,7 +553,6 @@ class ServingEngine:
     def submit(self, req: Request):
         # stamp arrival so TTFT covers queue wait, not just slot residency
         self._enq_vt[req.rid] = self.now()
-        self._enq_wall[req.rid] = time.perf_counter()
         self.queue.append(req)
 
     def _record_ttft(self, req: Request):
@@ -554,9 +561,6 @@ class ServingEngine:
         vt = t - self._enq_vt.pop(req.rid, t)
         self.ttft_vt_samples.append(vt)
         self.metrics.histogram("ttft", tenant=req.tenant).record(vt)
-        wall = self._enq_wall.pop(req.rid, None)
-        if wall is not None:
-            self.ttft_wall_samples.append(time.perf_counter() - wall)
 
     def _admit_common(self, slot_idx: int, slot: _Slot, req: Request):
         """Slot bookkeeping shared by both admission paths. Returns the
@@ -782,7 +786,7 @@ class ServingEngine:
         self._sync_registry_books()
         return d
 
-    def _account_decode(self):
+    def _account_decode(self, kind: str):
         """Per decode step: every active sequence touches all its KV pages
         (attention reads the whole cache) — that stream drives placement,
         prefetch, the profiler and the tracer.
@@ -798,7 +802,20 @@ class ServingEngine:
         masks the rest), and its segment carries ROLE_PREFILL into the
         counter plane's role accumulator — the mixed prefill/decode
         dispatch stays ONE kernel pass, roles ride alongside the segment
-        index exactly like tenant rows do."""
+        index exactly like tenant rows do.
+
+        Phases: ``tier.lookup`` (the step's page walks and the segmented
+        lookup's host side), then ``engine.account`` (the per-slot books)."""
+        with self._phase("tier.lookup", kind=kind):
+            segs, segmented = self._lookup_walks()
+        if segs:
+            with self._phase("engine.account", kind=kind):
+                self._account_slots(segs, segmented)
+
+    def _lookup_walks(self):
+        """Every active slot's page walk, and the step's one segmented
+        tiered-gather dispatch over them. Returns the walks and whether the
+        lookup was segmented."""
         segs = []
         for slot_idx, slot in enumerate(self.slots):
             if not slot.active:
@@ -815,7 +832,7 @@ class ServingEngine:
             if pages.size:
                 segs.append((slot_idx, slot, pages, role))
         if not segs:
-            return
+            return segs, False
         segmented = self.tiered is not None and self.ecfg.segmented_lookup
         if segmented:
             ids = np.concatenate([p for _, _, p, _ in segs])
@@ -836,6 +853,12 @@ class ServingEngine:
             if self.ecfg.tiered_verify:
                 err = float(jnp.max(jnp.abs(rows - self.tiered.lookup_flat(ids))))
                 self.tiered_max_err = max(self.tiered_max_err, err)
+        return segs, segmented
+
+    def _account_slots(self, segs, segmented: bool):
+        """The per-slot books of one step's page walks: prefetcher,
+        profiler, tracer, access hooks (and, off the segmented path, the
+        per-slot lookups and tier books)."""
         far_total = n_total = 0
         for slot_idx, slot, pages, _role in segs:
             far = self.placement.tier[pages] == 1
@@ -872,29 +895,27 @@ class ServingEngine:
 
     def _finish_chunk(self, slot_idx: int, slot: _Slot):
         """Post-dispatch bookkeeping for one prefilling slot: advance the
-        chunk cursor, push the prompt pages this chunk completed through
-        the tiered write path (each page keyed by its last prefilled
-        token, exactly as the whole-slot admit seeds them), and — when
-        the final prompt token just landed — close TTFT: the emit column
-        captured the request's first generated token into next_tokens."""
+        chunk cursor, name the prompt pages this chunk completed for the
+        tiered write path (each page keyed by its last prefilled token,
+        exactly as the whole-slot admit seeds them; ``step`` writes them
+        after the retire loop), and — when the final prompt token just
+        landed — close TTFT: the emit column captured the request's first
+        generated token into next_tokens. Returns the write as
+        ``(positions, pages)``, empty without a tiered store."""
         start, end = self._step_chunks[slot_idx]
         slot.chunk.pos = end
         slot.chunks_done += 1
+        w_pages: List[int] = []
+        w_pos: List[int] = []
         if self.tiered is not None:
             pages = self.pagetable.seqs[slot.seq_id]
             ps = self.ecfg.page_size
             total = slot.chunk.total
-            w_pages: List[int] = []
-            w_pos: List[int] = []
             for i, pid in enumerate(pages):
                 endpos = min((i + 1) * ps, total)
                 if start < endpos <= end:
                     w_pages.append(pid)
                     w_pos.append(endpos - 1)
-            if w_pages:
-                self._tiered_write(
-                    self.cache, [slot_idx] * len(w_pages), w_pos, w_pages
-                )
         t = self.now()
         if self.recorder is not None:
             self.recorder.span(
@@ -923,6 +944,7 @@ class ServingEngine:
                     chunks=slot.chunks_done,
                     shared_pages=slot.shared_pages,
                 )
+        return w_pos, w_pages
 
     def step(self) -> int:
         """One engine iteration: admit -> decode -> account -> retire.
@@ -935,34 +957,79 @@ class ServingEngine:
         plain fused (B, 1) decode. Either way: one model dispatch, zero
         mandatory host syncs.
 
+        With phases on (``obs.FlightRecorder(phases=True)``) the step is
+        one ``engine.step`` phase, its ``kind`` (``decode`` or ``chunk``)
+        set once admission has run, holding phases that do not overlap:
+        ``engine.admit``, ``engine.plan`` (chunk steps), ``engine.dispatch``,
+        ``tier.lookup``, ``engine.account``, ``engine.retire``, ``tier.write``
+        and, at a placement-window boundary, ``tier.drain`` and
+        ``tier.placement``.
+
         Returns number of tokens decoded this step.
         """
-        self._admit()
-        active = [i for i, s in enumerate(self.slots) if s.active]
-        if not active:
-            return 0
-        if any(s.prefilling for s in self.slots):
-            tok, use_prompt, act, emit, spans = self._chunk_plan()
-            self._step_chunks = spans
-            self.next_tokens, self.cache = self._chunk_decode(
-                self.params,
-                self.cache,
-                self.next_tokens,
-                jnp.asarray(tok),
-                jnp.asarray(use_prompt),
-                jnp.asarray(act),
-                jnp.asarray(emit),
-            )
+        with self._phase("engine.step") as step_phase:
+            with self._phase("engine.admit"):
+                self._admit()
+            if not any(s.active for s in self.slots):
+                return 0
+            kind = "chunk" if any(s.prefilling for s in self.slots) else "decode"
+            step_phase.set_kind(kind)
+            self._m_steps[kind].inc()
+            return self._step(kind)
+
+    def _step(self, kind: str) -> int:
+        """``step`` after admission, with at least one slot active."""
+        ph = self._phase
+        if kind == "chunk":
+            with ph("engine.plan", kind=kind):
+                tok, use_prompt, act, emit, spans = self._chunk_plan()
+                self._step_chunks = spans
+            with ph("engine.dispatch", kind=kind):
+                self.next_tokens, self.cache = self._chunk_decode(
+                    self.params,
+                    self.cache,
+                    self.next_tokens,
+                    jnp.asarray(tok),
+                    jnp.asarray(use_prompt),
+                    jnp.asarray(act),
+                    jnp.asarray(emit),
+                )
         else:
             self._step_chunks = {}
             # one fused dispatch: decode + next-token argmax, cache donated —
             # tokens and cache stay on device, nothing reads back to host
-            self.next_tokens, self.cache = self._decode(
-                self.params, self.cache, self.next_tokens[:, None]
-            )
+            with ph("engine.dispatch", kind=kind):
+                self.next_tokens, self.cache = self._decode(
+                    self.params, self.cache, self.next_tokens[:, None]
+                )
         self.model_dispatches += 1
-        self._account_decode()
+        self._account_decode(kind)
+        with ph("engine.retire", kind=kind):
+            decoded, writes = self._retire()
+        if self.tiered is not None and writes:
+            with ph("tier.write", kind=kind):
+                for slots, positions, pages in writes:
+                    self._tiered_write(self.cache, slots, positions, pages)
+        # profiler-window boundary: the ONE host sync of the tiered path —
+        # drain the device counter plane into the host books, then run the
+        # TPP epoch (skipped when a fleet planner drives placement)
+        if self.engine_steps % self.ecfg.placement_window == 0:
+            with ph("tier.drain", kind=kind):
+                self.drain_tier_counters()
+            with ph("tier.placement", kind=kind):
+                self._placement_epoch()
+        return decoded
+
+    def _retire(self):
+        """The step's books after its dispatch: advance prefill chunks,
+        append each decoded token, retire finished requests, record the
+        writes, tick the profiler and tracer. Returns the tokens decoded
+        and the tiered writes the step still owes."""
         decoded = 0
+        # tiered writes as (slots, positions, pages), in the order the loop
+        # names them: the prompt pages each prefill chunk completed, then
+        # the decoded tokens' pages
+        writes: List[Tuple[List[int], List[int], List[int]]] = []
         written: List[int] = []
         written_tenant: List[str] = []
         written_slot: List[int] = []
@@ -972,7 +1039,9 @@ class ServingEngine:
             if not slot.active:
                 continue
             if slot.prefilling:
-                self._finish_chunk(slot_idx, slot)
+                w_pos, w_pages = self._finish_chunk(slot_idx, slot)
+                if w_pages:
+                    writes.append(([slot_idx] * len(w_pages), w_pos, w_pages))
                 continue
             written.append(self.pagetable.append_token(slot.seq_id))
             written_tenant.append(slot.request.tenant)
@@ -1015,11 +1084,11 @@ class ServingEngine:
             # the decoded token's KV write — gives the access stream a real
             # R:W mix (Table 6 validation compares read:write ratios)
             w = np.asarray(written, np.int64)
-            if self.tiered is not None:
-                # the write is executed on device too: every written page's
-                # payload row lands in its current tier (quantized if far),
-                # one batched scatter for the whole step
-                self._tiered_write(self.cache, written_slot, written_pos, written)
+            # the write is executed on device too (``step`` issues it after
+            # this loop): every written page's payload row lands in its
+            # current tier (quantized if far), one batched scatter for the
+            # whole step
+            writes.append((written_slot, written_pos, written))
             self.profiler.record("kv", w, rw="w")
             by_tenant: Dict[str, List[int]] = {}
             for page, tenant in zip(written, written_tenant):
@@ -1033,37 +1102,35 @@ class ServingEngine:
         self.engine_steps += 1
         self.profiler.tick()
         self.tracer.tick()
-        # profiler-window boundary: the ONE host sync of the tiered path —
-        # drain the device counter plane into the host books, then run the
-        # TPP epoch (skipped when a fleet planner drives placement)
-        if self.engine_steps % self.ecfg.placement_window == 0:
-            self.drain_tier_counters()
-            # degraded mode suspends placement planning and prefetch
-            # promotion — there is no near capacity to plan into — but the
-            # boundary drain above still runs: far hits keep charging the
-            # books at the same cadence, so degraded books stay exact
-            if not self.external_placement and not self.degraded:
-                wins = self.profiler.windows("kv")
-                if wins:
-                    self.placement.step(wins[-1])
-                    self._sync_device_tiers()
-            # trace-driven prefetch issue window: runs right after the
-            # boundary drain, so its apply_placement-style migration sees a
-            # clean counter plane and costs ZERO additional host syncs
-            # (drain_counters early-returns while the plane is clean)
-            if self.ecfg.prefetch_promote and not self.degraded:
-                if self.prefetch.predictor == "trace":
-                    # local training is tenant-partitioned like the fleet
-                    # push: trace streams are seq ids, and _seq_tenant maps
-                    # them back to the tenant whose table they train
-                    self.prefetch.load_successors(
-                        train_tenant_successors(
-                            self.tracer.windows[-32:], self._seq_tenant
-                        ),
-                        merge=True,
-                    )
-                self._prefetch_window()
-        return decoded
+        return decoded, writes
+
+    def _placement_epoch(self):
+        """The window boundary's placement work, after the drain."""
+        # degraded mode suspends placement planning and prefetch
+        # promotion — there is no near capacity to plan into — but the
+        # boundary drain still runs: far hits keep charging the books at
+        # the same cadence, so degraded books stay exact
+        if not self.external_placement and not self.degraded:
+            wins = self.profiler.windows("kv")
+            if wins:
+                self.placement.step(wins[-1])
+                self._sync_device_tiers()
+        # trace-driven prefetch issue window: runs right after the
+        # boundary drain, so its apply_placement-style migration sees a
+        # clean counter plane and costs ZERO additional host syncs
+        # (drain_counters early-returns while the plane is clean)
+        if self.ecfg.prefetch_promote and not self.degraded:
+            if self.prefetch.predictor == "trace":
+                # local training is tenant-partitioned like the fleet
+                # push: trace streams are seq ids, and _seq_tenant maps
+                # them back to the tenant whose table they train
+                self.prefetch.load_successors(
+                    train_tenant_successors(
+                        self.tracer.windows[-32:], self._seq_tenant
+                    ),
+                    merge=True,
+                )
+            self._prefetch_window()
 
     def _prefetch_window(self) -> int:
         """Chase each predicted page chain and promote the predicted FAR
@@ -1409,7 +1476,6 @@ class ServingEngine:
         out: List[Tuple[Request, int]] = []
         for req in self.queue:
             self._enq_vt.pop(req.rid, None)
-            self._enq_wall.pop(req.rid, None)
             out.append((req, 0))
         self.queue.clear()
         for slot in self.slots:
@@ -1420,7 +1486,6 @@ class ServingEngine:
             self.pagetable.free_sequence(slot.seq_id)
             self.prefetch.drop_stream(slot.seq_id)
             self._enq_vt.pop(slot.seq_id, None)
-            self._enq_wall.pop(slot.seq_id, None)
             slot.seq_id = -1
             slot.request = None
             slot.chunk = None

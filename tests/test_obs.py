@@ -19,10 +19,15 @@ The contracts this file pins (ISSUE 6 acceptance):
 5. ``tenant_report`` queue-wait p50/p99 now come from the mergeable
    histogram and pin against the legacy np.percentile values on a seeded
    run (within one exponential bucket).
+6. Wall-clock phases: with phases on, a chunk step and a decode step each
+   open ``engine.step`` around their child phases, in order, none
+   overlapping, each counted once by phase and kind; without a recorder,
+   or with its phases off, a step reads no clock and annotates nothing.
 """
 import dataclasses
 import json
 import math
+import time
 
 import jax
 import numpy as np
@@ -30,7 +35,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.workloads import get_profile
-from repro.data.requests import RequestGenerator, interleave
+from repro.data.requests import Request, RequestGenerator, interleave
 from repro.fleet import (
     AdmissionController,
     SLOModel,
@@ -455,3 +460,117 @@ def test_default_recorder_env_flag(monkeypatch):
     rec = default_recorder()
     assert rec is not None and default_recorder() is rec
     set_default_recorder(None)
+
+
+# ---------------------------------------------------------------------------
+# 6. wall-clock phases of the engine step
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps every span as
+    ``[name, enter_ns, exit_ns, args]`` in the order they open."""
+
+    def __init__(self):
+        self.spans = []
+
+    def __call__(self, name, **args):
+        spans = self.spans
+
+        class Annotation:
+            def __enter__(self):
+                self.rec = [name, time.perf_counter_ns(), None, dict(args)]
+                spans.append(self.rec)
+
+            def __exit__(self, *exc):
+                self.rec[2] = time.perf_counter_ns()
+
+            def set_metadata(self, **kw):
+                self.rec[3].update(kw)
+
+        return Annotation()
+
+
+def test_phase_counts_calls_and_time_by_phase_and_kind(monkeypatch):
+    import repro.obs as obs
+
+    ann = _Annotations()
+    monkeypatch.setattr(obs, "TraceAnnotation", ann)
+    assert FlightRecorder().phases is False
+    rec = FlightRecorder(phases=True)
+    with rec.phase("engine.step") as p:
+        with rec.phase("engine.dispatch", kind="decode", n=3):
+            pass
+        p.set_kind("decode")
+    with rec.phase("engine.step"):
+        pass
+    c = rec.metrics.counter
+    assert c("phase_calls", phase="engine.step", kind="decode").value == 1
+    assert c("phase_calls", phase="engine.step").value == 1
+    assert c("phase_calls", phase="engine.dispatch", kind="decode").value == 1
+    step_ns = c("phase_ns", phase="engine.step", kind="decode").value
+    assert step_ns >= c("phase_ns", phase="engine.dispatch", kind="decode").value > 0
+    names = [(s[0], s[3]) for s in ann.spans]
+    assert names == [("engine.step", {"kind": "decode"}),
+                     ("engine.dispatch", {"kind": "decode", "n": 3}),
+                     ("engine.step", {})]
+
+
+CHUNK_STEP = ["engine.admit", "engine.plan", "engine.dispatch", "tier.lookup",
+              "engine.account", "engine.retire", "tier.write"]
+DECODE_STEP = ["engine.admit", "engine.dispatch", "tier.lookup", "engine.account",
+               "engine.retire", "tier.write", "tier.drain", "tier.placement"]
+
+
+def _prompted_engine(recorder):
+    """A chunked engine holding one request whose 5-token prompt fits one
+    chunk: its first step is a chunk step, its second a decode step that
+    closes a placement window."""
+    cfg, eng = _mk_engine(recorder=recorder, max_batch=2, prefill_chunk=8,
+                          placement_window=2)
+    prompt = np.arange(1, 6, dtype=np.int32)
+    eng.submit(Request(0, prompt, 3, -1, 0.0))
+    return eng
+
+
+def test_step_phases_nest_in_order_and_are_counted(monkeypatch):
+    import repro.obs as obs
+
+    ann = _Annotations()
+    monkeypatch.setattr(obs, "TraceAnnotation", ann)
+    rec = FlightRecorder(phases=True)
+    eng = _prompted_engine(rec)
+    for kind, children in (("chunk", CHUNK_STEP), ("decode", DECODE_STEP)):
+        ann.spans.clear()
+        eng.step()
+        (step, *kids) = ann.spans
+        assert step[0] == "engine.step" and step[3] == {"kind": kind}
+        assert [k[0] for k in kids] == children
+        assert all(k[3] == {"kind": kind} for k in kids if k[0] != "engine.admit")
+        # the children do not overlap and fit inside the step
+        bounds = [step[1]] + [t for k in kids for t in k[1:3]] + [step[2]]
+        assert bounds == sorted(bounds)
+    c = rec.metrics.counter
+    for kind, children in (("chunk", CHUNK_STEP), ("decode", DECODE_STEP)):
+        assert c("phase_calls", phase="engine.step", kind=kind).value == 1
+        assert eng.metrics.counter("engine_steps", kind=kind).value == 1
+        for name in children[1:]:
+            assert c("phase_calls", phase=name, kind=kind).value == 1, (name, kind)
+    assert c("phase_calls", phase="engine.admit").value == 2
+
+
+@pytest.mark.parametrize("phases_off", ["no recorder", "recorder, phases off"])
+def test_step_without_phases_reads_no_clock(monkeypatch, phases_off):
+    import repro.obs as obs
+
+    eng = _prompted_engine(None if phases_off == "no recorder" else FlightRecorder())
+
+    def refuse(*a, **k):
+        raise AssertionError("a step without phases read the clock or annotated")
+
+    monkeypatch.setattr(time, "perf_counter_ns", refuse)
+    monkeypatch.setattr(obs, "TraceAnnotation", refuse)
+    for _ in range(3):  # a chunk step, then decode steps over a window boundary
+        eng.step()
+    assert eng.tokens_decoded == 2
+    steps = {k: eng.metrics.counter("engine_steps", kind=k).value for k in ("chunk", "decode")}
+    assert steps == {"chunk": 1, "decode": 2}

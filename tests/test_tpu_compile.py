@@ -18,6 +18,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.tiered_gather.ops import (
+    LOOKUP_KERNEL,
+    SEGMENTED_KERNEL,
     gather_rows,
     tiered_lookup_counted,
     tiered_lookup_segments,
@@ -94,6 +96,30 @@ def test_tiered_lookup_counted_compiles(shape, d):
     compiled = _compile(fn, _store_args(shape, d))
     rows, near, far = compiled.out_info
     assert rows.shape == (N_IDS, d) and near.shape == far.shape == ()
+
+
+@pytest.mark.parametrize(
+    "entry,kernel",
+    [("segments", SEGMENTED_KERNEL), ("counted", LOOKUP_KERNEL)],
+)
+def test_tiered_gather_kernels_carry_their_names(shape, entry, kernel):
+    """The v5e lowering names each tiered-gather kernel's operation after
+    the kernel, so a device trace shows it under that name."""
+    args = _store_args(shape, 128)
+    if entry == "segments":
+        fn = functools.partial(
+            tiered_lookup_segments, n_segments=N_SEGMENTS, interpret=False
+        )
+        args += (shape((N_IDS,), jnp.int32),)
+    else:
+        fn = functools.partial(tiered_lookup_counted, interpret=False)
+    kernel_ops = [
+        line.split(" = ", 1)[0].strip().lstrip("%")
+        for line in _compile(fn, args).as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert len(kernel_ops) == 1, kernel_ops
+    assert kernel_ops[0].split(".")[0] == kernel
 
 
 @D_PARAMS
