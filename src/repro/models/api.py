@@ -109,6 +109,15 @@ class ModelAPI:
     def decode(self, params: dict, cache: dict, tokens: Array):
         return _MODULES[self.family].decode_step(params, self.cfg, cache, tokens)
 
+    @property
+    def block_decode(self) -> bool:
+        """Whether the family's module feeds a (B, C) token block through
+        one pass (``decode_block``) rather than C single-token decodes."""
+        return hasattr(_MODULES[self.family], "decode_block")
+
+    def decode_block(self, params: dict, cache: dict, tokens: Array, n: Array, out_cols: Array):
+        return _MODULES[self.family].decode_block(params, self.cfg, cache, tokens, n, out_cols)
+
     # ------------------------------------------------------------------
     # assigned-shape input stand-ins (global shapes; no allocation)
 

@@ -296,6 +296,55 @@ def attention_decode(
     return o.reshape(b, hq, lq, d).astype(q.dtype)
 
 
+CACHE_BLOCK_K = 512  # key positions per online-softmax step of attention_block
+
+
+def attention_block(q: Array, k: Array, v: Array, kv_length: Array) -> Array:
+    """Attention of C query columns per row over a cache, each column with
+    its own valid length: the multi-token form of ``attention_decode``.
+
+    q: (B, Hq, C, D); k, v: (B, Hkv, S, D); kv_length: (B, C) — query
+    column j of row i sees the keys at positions < kv_length[i, j].
+
+    Online softmax over key blocks of ``CACHE_BLOCK_K`` positions (one block
+    when that does not divide S), sliced from the cache in place, so only one
+    block's f32 scores are live. On a TPU v5e, smollm-360m's chunk step at
+    16 slots of 2048 positions and 128 columns took 57.5 ms with this form
+    and 79.5 ms with one masked einsum over all S keys, the rest of the
+    step the same.
+    """
+    b, hq, lq, d = q.shape
+    hkv, s_len = k.shape[1], k.shape[2]
+    block_k = CACHE_BLOCK_K if s_len % CACHE_BLOCK_K == 0 else s_len
+    scale = 1.0 / math.sqrt(d)
+    qg = _expand_gqa(q, hkv)  # (B, Hkv, G, C, D)
+
+    def body(carry, i):
+        m, l, acc = carry
+        kb = jax.lax.dynamic_slice_in_dim(k, i * block_k, block_k, axis=2)
+        vb = jax.lax.dynamic_slice_in_dim(v, i * block_k, block_k, axis=2)
+        s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kb, preferred_element_type=jnp.float32) * scale
+        kv_pos = i * block_k + jnp.arange(block_k)
+        bias = jnp.where(kv_pos[None, None, :] < kv_length[:, :, None], 0.0, NEG_INF)
+        s = s + bias[:, None, None]  # (B, C, block) bias, as in _block_scores
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        l = l * corr + p.sum(axis=-1)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bhgqk,bhkd->bhgqd", p.astype(vb.dtype), vb, preferred_element_type=jnp.float32
+        )
+        return (m_new, l, acc), None
+
+    m0 = jnp.full(qg.shape[:-1], NEG_INF, jnp.float32)
+    a0 = jnp.zeros(qg.shape, jnp.float32)
+    (_, l, acc), _ = jax.lax.scan(
+        body, (m0, jnp.zeros_like(m0), a0), jnp.arange(s_len // block_k)
+    )
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.reshape(b, hq, lq, d).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # MLP
 

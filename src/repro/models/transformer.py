@@ -288,6 +288,33 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict, tokens: Array, mrop
     return logits, new_cache
 
 
+def decode_block(
+    params: dict, cfg: ModelConfig, cache: dict, tokens: Array, n: Array, out_cols: Array
+):
+    """C tokens per row in one pass. tokens: (B, C); row i feeds its first
+    ``n[i]`` tokens (0 leaves the row's cache and length as they were);
+    ``out_cols`` (B,) names the column whose logits each row returns.
+    Returns (logits (B, 1, Vp), cache')."""
+    h = _embed_in(params, cfg, tokens)
+    lengths = cache["lengths"]
+
+    def step(h, xs):
+        layer, kc, vc = xs
+        layer = common.constrain_tree(layer, layer_specs(cfg), common.dt(cfg.compute_dtype))
+        x = common.rms_norm(h, layer["ln1"], cfg.norm_eps)
+        a, kc, vc = attention.apply_block(layer["attn"], cfg, x, kc, vc, lengths, n)
+        h = h + a
+        x = common.rms_norm(h, layer["ln2"], cfg.norm_eps)
+        m = layer["mlp"]
+        h = h + common.swiglu(x, m["w_gate"], m["w_up"], m["w_down"])
+        return h, (kc, vc)
+
+    h, (ks, vs) = jax.lax.scan(step, h, (params["layers"], cache["k"], cache["v"]))
+    h = jnp.take_along_axis(h, out_cols[:, None, None], axis=1)  # (B, 1, D)
+    logits = _logits_out(params, cfg, h)
+    return logits, {"k": ks, "v": vs, "lengths": lengths + n}
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     return attention.init_cache(cfg, cfg.n_layers, batch, max_len, dtype)
 

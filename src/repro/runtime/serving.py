@@ -49,8 +49,9 @@ Continuous batching + chunked prefill (``EngineConfig.prefill_chunk``):
 with a positive chunk budget, ``step`` is a vLLM-style continuous-batching
 step — new requests are admitted into freed slots every step, and their
 prompts are fed in fixed-token-budget chunks INTERLEAVED with the decode
-tokens of co-resident slots inside the SAME single jitted dispatch (a
-masked column scan over the family decode step; every engine step runs
+tokens of co-resident slots inside the SAME single jitted dispatch (one
+(B, C) block pass where the model has one, else a masked column scan over
+the family decode step — ``make_chunk_step``; every engine step runs
 exactly one model executable and one tiered-gather dispatch regardless of
 the prefill/decode mix). Prefill-chunk KV page reads ride the segmented
 gather as ROLE_PREFILL segments next to the decode walks, prefill chunks
@@ -154,6 +155,83 @@ def _slot_zero(leaf, slot_idx):
     if leaf.ndim == 1:
         return leaf.at[slot_idx].set(jnp.zeros((), leaf.dtype))
     return leaf.at[:, slot_idx].set(jnp.zeros((), leaf.dtype))
+
+
+@jax.jit
+def _kv_payload(k, v, slots, positions):
+    """Payload rows of n (slot, position) pairs of a (L, B, H, S, D) KV
+    cache: each pair's k then v vectors, flattened across layers and heads,
+    as (n, 2 * L * H * D) f32.
+
+    One dynamic slice per pair and cache, not one gather: on a TPU an XLA
+    gather over the batch and position axes first copies the whole cache
+    into another layout, a full copy of k and of v on every call."""
+    n_layers, _, n_heads, _, head_dim = k.shape
+
+    def at(c, i):
+        start = (0, slots[i], 0, positions[i], 0)
+        size = (n_layers, 1, n_heads, 1, head_dim)
+        return jax.lax.dynamic_slice(c, start, size).reshape(-1)
+
+    rows = [jnp.concatenate([at(k, i), at(v, i)]) for i in range(slots.shape[0])]
+    return jnp.stack(rows).astype(jnp.float32)
+
+
+def make_chunk_step(api: ModelAPI):
+    """The continuous-batching step, ``(params, cache, nxt, tok, use_prompt,
+    active, emit) -> (nxt, cache)`` over the (B, C) masks of
+    ``ServingEngine._chunk_plan``: prompt rows take their chunk tokens,
+    decode rows the fed-back next token in column 0, and a row's active
+    columns are a prefix of it. ``emit`` marks the column whose argmax is a
+    row's next fed token: column 0 for decode rows, the final-prompt-token
+    column for a prompt that completes this step (its first generated
+    token); a row without one keeps its ``nxt``. Inactive rows keep their
+    cache.
+
+    A family whose model decodes a token block in one pass
+    (``api.block_decode``) runs the whole (B, C) block through it. Any other
+    family scans the C columns through its single-token decode step,
+    gating every cache leaf per column (batch axis 0 for 1-D leaves, else
+    axis 1 — the convention ``_write_slot`` relies on).
+    """
+    vocab = api.cfg.vocab_size
+    if api.block_decode:
+
+        def _chunk_step(params, cache, nxt, tok, use_prompt, active, emit):
+            n = active.sum(axis=1, dtype=jnp.int32)
+            tokens = jnp.where(use_prompt, tok, nxt[:, None])
+            out_col = (tok.shape[1] - 1) - jnp.argmax(emit[:, ::-1], axis=1)
+            logits, cache = api.decode_block(params, cache, tokens, n, out_col)
+            out = jnp.argmax(logits[:, 0, :vocab], axis=-1).astype(jnp.int32)
+            return jnp.where(emit.any(axis=1), out, nxt), cache
+
+        return _chunk_step
+
+    serve = make_serve_step(api, vocab=vocab)
+
+    def _chunk_step(params, cache, nxt, tok, use_prompt, active, emit):
+        def col(carry, xs):
+            cache, nxt = carry
+            tok_c, up_c, act_c, em_c = xs
+            t = jnp.where(up_c, tok_c, nxt)
+            out, new_cache = serve(params, cache, t[:, None])
+
+            def gate(new, old):
+                if new.ndim == 1:
+                    return jnp.where(act_c, new, old)
+                m = act_c.reshape((1, -1) + (1,) * (new.ndim - 2))
+                return jnp.where(m, new, old)
+
+            cache = jax.tree.map(gate, new_cache, cache)
+            nxt = jnp.where(em_c, out[:, 0], nxt)
+            return (cache, nxt), None
+
+        (cache, nxt), _ = jax.lax.scan(
+            col, (cache, nxt), (tok.T, use_prompt.T, active.T, emit.T)
+        )
+        return nxt, cache
+
+    return _chunk_step
 
 
 @dataclasses.dataclass
@@ -380,42 +458,10 @@ class ServingEngine:
 
             api._jit_decode = jax.jit(_decode_step, donate_argnums=(1,))
         self._decode = api._jit_decode
-        # the continuous-batching step: a masked scan over the chunk's
-        # token columns through the same family decode step — ONE jitted
-        # dispatch covers every prefill chunk and decode token of the step.
-        # Per column, prompt rows take their chunk token, decode rows take
-        # the fed-back next token; inactive rows keep their cache via a
-        # per-leaf where (batch axis 0 for 1-D leaves, else axis 1 — the
-        # same convention _write_slot relies on). ``emit`` marks the column
-        # whose argmax is a row's next fed token: column 0 for decode rows,
-        # the final-prompt-token column for a prompt that completes this
-        # step (its first generated token).
+        # the continuous-batching step (make_chunk_step): ONE jitted
+        # dispatch covers every prefill chunk and decode token of the step
         if not hasattr(api, "_jit_chunk_decode"):
-            chunk_serve = make_serve_step(api, vocab=self.cfg.vocab_size)
-
-            def _chunk_step(params, cache, nxt, tok, use_prompt, active, emit):
-                def col(carry, xs):
-                    cache, nxt = carry
-                    tok_c, up_c, act_c, em_c = xs
-                    t = jnp.where(up_c, tok_c, nxt)
-                    out, new_cache = chunk_serve(params, cache, t[:, None])
-
-                    def gate(new, old):
-                        if new.ndim == 1:
-                            return jnp.where(act_c, new, old)
-                        m = act_c.reshape((1, -1) + (1,) * (new.ndim - 2))
-                        return jnp.where(m, new, old)
-
-                    cache = jax.tree.map(gate, new_cache, cache)
-                    nxt = jnp.where(em_c, out[:, 0], nxt)
-                    return (cache, nxt), None
-
-                (cache, nxt), _ = jax.lax.scan(
-                    col, (cache, nxt), (tok.T, use_prompt.T, active.T, emit.T)
-                )
-                return nxt, cache
-
-            api._jit_chunk_decode = jax.jit(_chunk_step, donate_argnums=(1,))
+            api._jit_chunk_decode = jax.jit(make_chunk_step(api), donate_argnums=(1,))
         self._chunk_decode = api._jit_chunk_decode
         # slot-buffer donation across join/leave churn: the batched cache
         # is threaded through jitted, donated updates — the whole-slot
@@ -524,14 +570,10 @@ class ServingEngine:
         """
         k = self._dense_kv(cache)
         if k is not None:
-            bi = jnp.asarray(batch_idxs, jnp.int32)
-            pos = jnp.asarray(positions, jnp.int32)
-            # advanced indices (batch, seq-pos) broadcast together and land
-            # in front: (n, L, H, Dh) per store
-            kk = k[:, bi, :, pos, :]
-            vv = cache["v"][:, bi, :, pos, :]
-            kv = jnp.concatenate([kk, vv], axis=1)  # (n, 2L, H, Dh)
-            return kv.reshape(len(positions), -1).astype(jnp.float32)
+            return _kv_payload(
+                k, cache["v"], jnp.asarray(batch_idxs, jnp.int32),
+                jnp.asarray(positions, jnp.int32),
+            )
         pids = np.asarray(page_ids, np.int64)
         return jnp.asarray(
             counter_rows(self._seed, pids, self._page_wver[pids], self.tiered.row_dim)
@@ -669,7 +711,7 @@ class ServingEngine:
 
     def _chunk_plan(self):
         """Column plan for one continuous-batching step: (B, C) token ids
-        plus the use-prompt / active / emit masks the chunk scan consumes,
+        plus the use-prompt / active / emit masks the chunk step consumes,
         and the per-slot ``(start, end)`` prompt intervals this dispatch
         advances. Decode slots occupy column 0 only; each prefilling slot
         takes up to ``prefill_chunk`` prompt tokens and emits (captures its
@@ -951,7 +993,7 @@ class ServingEngine:
 
         Continuous batching: ``_admit`` runs at the top of EVERY step, so
         freed slots refill immediately. When any slot is mid-prefill the
-        step dispatches the chunk scan — prefill chunks and decode tokens
+        step dispatches the chunk step — prefill chunks and decode tokens
         share ONE jitted executable (and one segmented tiered-gather pass
         in ``_account_decode``); steady-state decode-only steps take the
         plain fused (B, 1) decode. Either way: one model dispatch, zero

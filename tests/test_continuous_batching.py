@@ -9,10 +9,12 @@ What this file pins:
 2. Finite-chunk token equivalence: the chunked engine produces exactly the
    whole-slot engine's token stream for every request — the prompt-
    completing chunk emits the same first token ``api.prefill``'s argmax
-   would have, and every subsequent decode token matches.
+   would have, and every subsequent decode token matches. Pinned for the
+   dense family (the block pass) and for the ssm and hybrid families (the
+   column scan over their single-token decode step).
 3. Chunk-boundary properties: prompt length vs chunk budget edge cases
    (L == C, L = C ± 1, L < C, L = kC, L = kC + 1) take exactly
-   ceil(L / C) prefill steps, then decode to completion.
+   ceil(L / C) prefill steps, then decode to completion, on both paths.
 4. Slot reuse after early completion: a request admitted into a recycled
    slot (jitted zero-reset, donated buffers) decodes the same stream as on
    a fresh engine.
@@ -34,29 +36,37 @@ from repro.data.requests import Request, RequestGenerator
 from repro.models.api import get_model
 from repro.runtime.serving import EngineConfig, ServingEngine
 
-_CFG = get_config("smollm-360m").reduced()
-_API = get_model(_CFG)  # one api => engines share the cached jitted steps
-_PARAMS = None
+_DENSE = "smollm-360m"
+# chunkable families without a block pass: their chunk step is the scan
+_SCANNED = ("rwkv6-7b", "zamba2-1.2b")  # ssm, hybrid
+# one api per arch => engines share the cached jitted steps
+_APIS = {}
+_PARAMS = {}
 
 
-def _mk(**ekw):
-    global _PARAMS
-    if _PARAMS is None:
-        _PARAMS = _API.init(jax.random.PRNGKey(0))
+def _api(arch):
+    if arch not in _APIS:
+        _APIS[arch] = get_model(get_config(arch).reduced())
+        _PARAMS[arch] = _APIS[arch].init(jax.random.PRNGKey(0))
+    return _APIS[arch]
+
+
+def _mk(arch=_DENSE, **ekw):
+    api = _api(arch)
     kw = dict(
         max_batch=4, max_len=64, n_pages=256, near_frac=0.02,
         placement_window=4, device_tiering=True, tiered_identity_scales=True,
     )
     kw.update(ekw)
-    return ServingEngine(_API, _PARAMS, EngineConfig(**kw), seed=0)
+    return ServingEngine(api, _PARAMS[arch], EngineConfig(**kw), seed=0)
 
 
-def _gen(seed=0, **pkw):
+def _gen(seed=0, arch=_DENSE, **pkw):
     prof = dataclasses.replace(
         get_profile("Web1"), prompt_mean=24, decode_mean=8,
         prefix_share=0.5, n_prefixes=2, **pkw,
     )
-    return RequestGenerator(prof, vocab_size=_CFG.vocab_size, seed=seed)
+    return RequestGenerator(prof, vocab_size=_api(arch).cfg.vocab_size, seed=seed)
 
 
 def _run_streams(eng, reqs, max_steps=300):
@@ -134,15 +144,17 @@ def test_infinite_budget_is_whole_slot_bit_exact():
 # 2. finite-chunk token equivalence
 
 
-def test_chunked_tokens_match_whole_slot():
-    gen = _gen(seed=3)
+@pytest.mark.parametrize("arch", (_DENSE,) + _SCANNED)
+def test_chunked_tokens_match_whole_slot(arch):
+    gen = _gen(seed=3, arch=arch)
     reqs = [next(gen) for _ in range(8)]
-    mono = _run_streams(_mk(), [dataclasses.replace(r) for r in reqs])
-    eng_c = _mk(prefill_chunk=8)
+    mono = _run_streams(_mk(arch), [dataclasses.replace(r) for r in reqs])
+    eng_c = _mk(arch, prefill_chunk=8)
     assert eng_c.chunking
+    assert eng_c.api.block_decode == (arch == _DENSE)
     chunked = _run_streams(eng_c, [dataclasses.replace(r) for r in reqs])
     assert set(mono) == set(chunked)
-    ref = _mk()  # for the t1 reference prefill passes only
+    ref = _mk(arch)  # for the t1 reference prefill passes only
     by_rid = {r.rid: r for r in reqs}
     for rid, m in mono.items():
         c = chunked[rid]
@@ -162,14 +174,19 @@ def test_chunked_tokens_match_whole_slot():
 # 3. chunk-boundary properties
 
 
+_LENGTHS = [1, 3, 7, 8, 9, 15, 16, 17, 24, 25]
+
+
 @pytest.mark.parametrize(
-    "L", [1, 3, 7, 8, 9, 15, 16, 17, 24, 25], ids=lambda v: f"L{v}"
+    "L,arch",
+    [pytest.param(L, _DENSE, id=f"L{L}") for L in _LENGTHS]
+    + [pytest.param(L, _SCANNED[0], id=f"{_SCANNED[0]}-L{L}") for L in _LENGTHS],
 )
-def test_chunk_boundaries(L):
+def test_chunk_boundaries(L, arch):
     C = 8
-    eng = _mk(max_batch=2, prefill_chunk=C)
+    eng = _mk(arch, max_batch=2, prefill_chunk=C)
     rng = np.random.default_rng(L)
-    tokens = rng.integers(0, _CFG.vocab_size, size=L).astype(np.int32)
+    tokens = rng.integers(0, eng.cfg.vocab_size, size=L).astype(np.int32)
     eng.submit(Request(0, tokens, 3, -1, 0.0))
     prefill_steps = 0
     steps = 0
@@ -191,9 +208,10 @@ def test_slot_reuse_after_early_completion():
     """A request admitted into a recycled slot (zero-reset, donated
     buffers) must decode exactly the stream it gets on a fresh engine."""
     rng = np.random.default_rng(11)
-    early = Request(0, rng.integers(0, _CFG.vocab_size, 10).astype(np.int32), 2, -1, 0.0)
-    stayer = Request(1, rng.integers(0, _CFG.vocab_size, 20).astype(np.int32), 12, -1, 0.0)
-    late = Request(2, rng.integers(0, _CFG.vocab_size, 12).astype(np.int32), 4, -1, 0.0)
+    vocab = _api(_DENSE).cfg.vocab_size
+    early = Request(0, rng.integers(0, vocab, 10).astype(np.int32), 2, -1, 0.0)
+    stayer = Request(1, rng.integers(0, vocab, 20).astype(np.int32), 12, -1, 0.0)
+    late = Request(2, rng.integers(0, vocab, 12).astype(np.int32), 4, -1, 0.0)
     # batch of 2: `late` queues until `early` retires, then reuses its slot
     shared = _run_streams(_mk(max_batch=2, prefill_chunk=4),
                           [dataclasses.replace(r) for r in (early, stayer, late)])

@@ -272,6 +272,27 @@ def _run_collect(eng, cfg, n_requests=6, seed=0):
     return np.array(tokens)
 
 
+def test_payload_rows_are_the_cache_rows():
+    """A written page's payload row is its token's k then v vectors, read
+    straight from the slot cache: per (slot, position) pair, in order, with
+    repeats, at the first and last slot and position."""
+    cfg, eng = _mk_engine(True)
+    rng = np.random.default_rng(3)
+    k = rng.standard_normal(eng.cache["k"].shape).astype(np.float32)
+    v = rng.standard_normal(eng.cache["v"].shape).astype(np.float32)
+    cache = dict(eng.cache, k=jnp.asarray(k, jnp.bfloat16), v=jnp.asarray(v, jnp.bfloat16))
+    k, v = (np.asarray(cache[n].astype(jnp.float32)) for n in ("k", "v"))
+    b, s = k.shape[1], k.shape[3]
+    slots, positions = [0, b - 1, 2, 2], [0, s - 1, 5, 5]
+    got = np.asarray(eng._payload_rows(cache, slots, positions, [0, 1, 2, 3]))
+    want = np.stack([
+        np.concatenate([k[:, i, :, p].reshape(-1), v[:, i, :, p].reshape(-1)])
+        for i, p in zip(slots, positions)
+    ])
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, want)
+
+
 @pytest.mark.slow
 def test_device_decode_bit_identical_to_host_accounting():
     """The acceptance oracle: identity scales => same tokens, same counters."""
